@@ -1,6 +1,7 @@
-"""Kernel micro-benches: interpret-mode correctness + jnp-reference timing.
+"""Kernel micro-benches: correctness vs oracle + jnp-reference timing.
 
-CPU wall-times are only indicative (the kernels TARGET TPU); what this
+The kernels run as the platform dictates (interpreted on CPU, Mosaic on
+TPU). CPU wall-times are only indicative (the kernels TARGET TPU); what this
 bench pins down is (a) allclose vs oracle at bench shapes and (b) the
 HBM-traffic model of each kernel vs its reference (the structural win).
 """
@@ -17,7 +18,6 @@ from repro.kernels import ops, ref
 
 def main(csv=True):
     rng = np.random.default_rng(0)
-    ops.set_interpret(True)
     checks = {}
 
     # hier_aggregate: N=32 clients, 1M-param block

@@ -5,7 +5,7 @@ benches. ``PYTHONPATH=src python -m benchmarks.run [--only name]
 ``--json`` writes per-bench machine-readable results (status, wall time,
 and whatever the bench's ``main()`` returned) — the start of the
 ``BENCH_*.json`` perf trajectory; CI runs the kernel bench through it as
-an interpret-mode smoke gate.
+a smoke gate (kernels interpreted on the CPU).
 
 Scenario mode runs one named ``repro.fed.scenarios`` registry entry
 end-to-end through the public ``ExperimentSpec`` API instead of the bench
@@ -32,6 +32,7 @@ from benchmarks import (
     table1_cost_model,
     table2_latency_energy,
 )
+from repro import compile_cache
 
 BENCHES = {
     "table1_cost_model": table1_cost_model.main,
@@ -76,6 +77,7 @@ def run_scenario(name: str, overrides) -> dict:
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--json", default=None, metavar="OUT.json",

@@ -70,6 +70,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro import compile_cache  # noqa: E402
 from repro.core import FedTopology, HierFAVGConfig  # noqa: E402
 from repro.data import FederatedBatcher, clustered_gaussians, make_partition  # noqa: E402
 from repro.dist.sharding import client_mesh  # noqa: E402
@@ -376,6 +377,7 @@ def run_population(name=POPULATION_SCENARIO, *, reps=3, intervals=4,
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="quick CI gate: headline shape only (plus the sharded "

@@ -21,6 +21,7 @@ sys.path.insert(0, ".")  # allow running from repo root
 
 from benchmarks.fig2_topologies import run_edge_only
 from benchmarks.common import first_reach, run_hierarchy_schedule, run_schedule
+from repro import compile_cache
 from repro.core import parse_fanouts
 
 # 50 clients under progressively less uniform trees (paper topology first)
@@ -37,6 +38,7 @@ DEFAULT_SWEEP = {
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--levels", type=int, default=0,
                     help="also sweep trees of this depth (0 = both 2 and 3)")

@@ -9,10 +9,12 @@ The whole experiment is the ``quickstart`` registry entry — tweak any axis
 with a dotted-path override, e.g.
 ``scenarios.get("quickstart", overrides=["schedule.kappas=6,2"])``.
 """
+from repro import compile_cache
 from repro.fed import scenarios
 
 
 def main():
+    compile_cache.enable()
     spec = scenarios.get("quickstart")
     print(spec.describe())
     runner, _ = spec.run_experiment()

@@ -12,10 +12,12 @@ dotted-path override on that spec.
 import argparse
 import time
 
+from repro import compile_cache
 from repro.fed import scenarios
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="10m", choices=["10m", "100m"])
     ap.add_argument("--rounds", type=int, default=24)

@@ -1652,7 +1652,6 @@ def build_sharded_super_round(
     padded_N), "step": (κ₂,)}`` — the engine reduces them host-side at
     flush time (phantom columns dropped).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = as_hierarchy(topology)
@@ -1748,16 +1747,16 @@ def build_sharded_super_round(
         batch_specs = jax.tree_util.tree_map(lambda _: batch_spec, batches)
         metric_specs = {"loss": P(None, None, axis), "gsq": P(None, None, axis), "step": P()}
         if masks is None:
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda s, b: body(s, b, None), mesh=mesh,
                 in_specs=(state_specs, batch_specs),
-                out_specs=(state_specs, metric_specs), check_rep=False,
+                out_specs=(state_specs, metric_specs), check_vma=False,
             )
             return fn(state, batches)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(state_specs, batch_specs, P(None, axis)),
-            out_specs=(state_specs, metric_specs), check_rep=False,
+            out_specs=(state_specs, metric_specs), check_vma=False,
         )
         return fn(state, batches, masks)
 
@@ -1897,7 +1896,6 @@ def build_sharded_cohort_super_round(
     Metrics stay per-client: ``{"loss": (κ₂, κ₁, padded_C), "gsq": (κ₂,
     κ₁, padded_C), "step": (κ₂,)}``, reduced host-side at flush.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = as_hierarchy(topology)
@@ -1997,16 +1995,16 @@ def build_sharded_cohort_super_round(
         batch_specs = jax.tree_util.tree_map(lambda _: batch_spec, batches)
         metric_specs = {"loss": P(None, None, axis), "gsq": P(None, None, axis), "step": P()}
         if masks is None:
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda s, b, w: body(s, b, w, None), mesh=mesh,
                 in_specs=(state_specs, batch_specs, P(axis)),
-                out_specs=(state_specs, metric_specs), check_rep=False,
+                out_specs=(state_specs, metric_specs), check_vma=False,
             )
             return fn(state, batches, weights)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(state_specs, batch_specs, P(axis), P(None, axis)),
-            out_specs=(state_specs, metric_specs), check_rep=False,
+            out_specs=(state_specs, metric_specs), check_vma=False,
         )
         return fn(state, batches, weights, masks)
 

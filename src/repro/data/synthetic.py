@@ -74,6 +74,13 @@ class TokenCorpus:
         return int(self.labels.max()) + 1
 
 
+# Largest vocab whose dense (classes, vocab, vocab) teacher is drawn whole;
+# above it each teacher row keeps SPARSE_SUCCESSORS successor tokens, so a
+# 32k vocab costs classes·vocab·K entries instead of classes·vocab² (64 GiB).
+DENSE_VOCAB_MAX = 2048
+SPARSE_SUCCESSORS = 64
+
+
 def token_corpus(
     rng: np.random.Generator,
     *,
@@ -86,19 +93,27 @@ def token_corpus(
     """Markov-teacher corpus: each class has its own sparse transition kernel.
 
     Lower `concentration` -> sparser kernels -> more divergent per-class
-    gradients (higher δ/Δ under label-skewed partitions).
+    gradients (higher δ/Δ under label-skewed partitions). Vocabularies above
+    ``DENSE_VOCAB_MAX`` draw each kernel row over ``SPARSE_SUCCESSORS``
+    random successor tokens instead of the whole vocab.
     """
+    sparse = vocab > DENSE_VOCAB_MAX
+    width = SPARSE_SUCCESSORS if sparse else vocab
     # Per-class transition matrices, Dirichlet rows (sparse-ish).
-    kernels = rng.dirichlet(np.full(vocab, concentration), size=(num_classes, vocab))
+    kernels = rng.dirichlet(np.full(width, concentration), size=(num_classes, vocab))
+    successors = (
+        rng.integers(0, vocab, size=(num_classes, vocab, width), dtype=np.int32) if sparse else None
+    )
     labels = rng.integers(0, num_classes, size=num_sequences).astype(np.int32)
     toks = np.empty((num_sequences, seq_len + 1), np.int32)
     toks[:, 0] = rng.integers(0, vocab, size=num_sequences)
     for t in range(seq_len):
         # vectorized per-class sampling
-        p = kernels[labels, toks[:, t]]  # (n, vocab)
+        p = kernels[labels, toks[:, t]]  # (n, width)
         cdf = np.cumsum(p, axis=1)
         u = rng.random((num_sequences, 1))
-        toks[:, t + 1] = (u < cdf).argmax(axis=1)
+        pick = (u < cdf).argmax(axis=1)
+        toks[:, t + 1] = successors[labels, toks[:, t], pick] if sparse else pick
     return TokenCorpus(tokens=toks, labels=labels)
 
 

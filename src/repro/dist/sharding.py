@@ -109,7 +109,6 @@ def moe_dispatch_exchange(buf_g, mode: str):
     mesh = _HINT_MESH
     if mesh is None:
         raise RuntimeError("moe_dispatch_exchange needs a hint mesh")
-    from jax.experimental.shard_map import shard_map
 
     g, e, c, d = buf_g.shape
 
@@ -120,7 +119,7 @@ def moe_dispatch_exchange(buf_g, mode: str):
         ge, ee = out.shape[0], out.shape[1]
         return jax.numpy.moveaxis(out, 0, 1).reshape(ee, ge * c, d)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=P("data", None, None, None),
@@ -136,7 +135,6 @@ def moe_combine_exchange(out_buf, flat_e_g, ranks_g, gates, mode: str, capacity:
     if mesh is None:
         raise RuntimeError("moe_combine_exchange needs a hint mesh")
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     e, gc, d = out_buf.shape
     g = gc // capacity
@@ -146,7 +144,7 @@ def moe_combine_exchange(out_buf, flat_e_g, ranks_g, gates, mode: str, capacity:
         back = jnp.moveaxis(buf.reshape(ee, g, capacity, d), 1, 0)  # (G, E/P, C, d)
         return jax.lax.all_to_all(back, "data", split_axis=0, concat_axis=1, tiled=True)
 
-    out_g = shard_map(
+    out_g = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=P("data", None, None),
@@ -299,6 +297,18 @@ def fed_state_shardings(mesh, axis: str, state, stacked_dim: int):
     return jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), specs, is_leaf=lambda x: isinstance(x, P)
     )
+
+
+def canonical_state_shardings(mesh, axis: str, state, stacked_dim: int, rows: int):
+    """NamedShardings for the canonical-order form (``rows`` clients) of a
+    stacked ``FedState`` whose client leaves now lead with ``stacked_dim``:
+    the client axis shards over ``axis`` when ``rows`` divides evenly, else
+    the client leaves replicate over the mesh. Either way the state stays
+    spread over the mesh instead of landing on one device."""
+    if rows % int(mesh.shape[axis]) == 0:
+        return fed_state_shardings(mesh, axis, state, stacked_dim)
+    rep = NamedSharding(mesh, P())
+    return jax.tree_util.tree_map(lambda _: rep, state)
 
 
 def fed_rules(cfg: ArchConfig, mesh) -> ShardingRules:

@@ -75,6 +75,21 @@ def _map_stacked(state: FedState, fn, lead: int) -> FedState:
     return map_stacked_fed_state(state, fn, lambda x: x, lead)
 
 
+def _relayout(state: FedState, index: np.ndarray, lead: int, shardings) -> FedState:
+    """Gather the ``lead`` stacked client rows of ``state`` by ``index``
+    straight into ``shardings`` in one compiled program, so a sharded state
+    never passes whole through one device; an identity index (no permutation,
+    no padding) skips the gather."""
+    if len(index) == lead and np.array_equal(index, np.arange(lead)):
+        return jax.device_put(state, shardings)
+    idx = jnp.asarray(index)
+    gather = jax.jit(
+        lambda s: _map_stacked(s, lambda x: jnp.take(x, idx, axis=0), lead),
+        out_shardings=shardings,
+    )
+    return gather(state)
+
+
 class SuperRoundEngine:
     """Drives a ``FederatedRunner``'s training loop one cloud interval per
     donated dispatch — client-sharded over the runner's mesh when one is
@@ -155,23 +170,20 @@ class SuperRoundEngine:
         out with the engine's NamedShardings (one upload per device)."""
         from repro.dist.sharding import fed_state_shardings
 
-        gather = jnp.asarray(self._gather)
-        padded = _map_stacked(
-            state, lambda x: jnp.take(x, gather, axis=0), self.runner.topology.num_clients
-        )
-        shardings = fed_state_shardings(
-            self.mesh, self.axis, padded, self.placement.padded_clients
-        )
-        return jax.device_put(padded, shardings)
+        n = self.runner.topology.num_clients
+        shardings = fed_state_shardings(self.mesh, self.axis, state, n)
+        return _relayout(state, self._gather, n, shardings)
 
     def _unshard_state(self, state: FedState) -> FedState:
-        """Placement-ordered padded state -> canonical client order on the
-        default device (phantom rows dropped by the inverse gather)."""
-        pos = jnp.asarray(self._positions)
-        out = _map_stacked(
-            state, lambda x: jnp.take(x, pos, axis=0), self.placement.padded_clients
+        """Placement-ordered padded state -> canonical client order, still
+        spread over the mesh (phantom rows dropped by the inverse gather)."""
+        from repro.dist.sharding import canonical_state_shardings
+
+        padded = self.placement.padded_clients
+        shardings = canonical_state_shardings(
+            self.mesh, self.axis, state, padded, self.runner.topology.num_clients
         )
-        return jax.device_put(out, jax.devices()[0])
+        return _relayout(state, self._positions, padded, shardings)
 
     def _canonical_params(self, state: FedState) -> PyTree:
         if self.mesh is None:
@@ -589,21 +601,17 @@ class CohortEngine:
         state laid out with the engine's NamedShardings."""
         from repro.dist.sharding import fed_state_shardings
 
-        gather = jnp.asarray(self._gather)
-        padded = _map_stacked(state, lambda x: jnp.take(x, gather, axis=0), self.cohort_size)
-        shardings = fed_state_shardings(
-            self.mesh, self.axis, padded, self.placement.padded_clients
-        )
-        return jax.device_put(padded, shardings)
+        shardings = fed_state_shardings(self.mesh, self.axis, state, self.cohort_size)
+        return _relayout(state, self._gather, self.cohort_size, shardings)
 
     def _unshard_state(self, state: FedState) -> FedState:
-        """Slot-placement-ordered padded state -> canonical cohort order on
-        the default device (phantom rows dropped)."""
-        pos = jnp.asarray(self._positions)
-        out = _map_stacked(
-            state, lambda x: jnp.take(x, pos, axis=0), self.placement.padded_clients
-        )
-        return jax.device_put(out, jax.devices()[0])
+        """Slot-placement-ordered padded state -> canonical cohort order,
+        still spread over the mesh (phantom rows dropped)."""
+        from repro.dist.sharding import canonical_state_shardings
+
+        padded = self.placement.padded_clients
+        shardings = canonical_state_shardings(self.mesh, self.axis, state, padded, self.cohort_size)
+        return _relayout(state, self._positions, padded, shardings)
 
     def _canonical_params(self, state: FedState) -> PyTree:
         if self.mesh is None:

@@ -193,7 +193,18 @@ class FederatedRunner:
             return init_cohort_state(
                 rng, params, self.optimizer, self.hier_config, self.participation.cohort_size
             )
-        return init_state(rng, params, self.optimizer, self.topology, self.hier_config)
+        build = lambda r, p: init_state(r, p, self.optimizer, self.topology, self.hier_config)
+        if self.mesh is None or self._state_shardings is not None:
+            return build(rng, params)
+        from repro.dist.sharding import canonical_state_shardings, client_axis_of
+
+        # a client mesh exists because the stacked state may not fit one
+        # device: build it laid out over the mesh, as the engine hands it back
+        n = self.topology.num_clients
+        shardings = canonical_state_shardings(
+            self.mesh, client_axis_of(self.mesh), jax.eval_shape(build, rng, params), n, n
+        )
+        return jax.jit(build, out_shardings=shardings)(rng, params)
 
     def restore_or_init(self, rng: jax.Array, params: PyTree) -> tuple:
         """(state, start_round). Resumes from the latest checkpoint if any."""

@@ -7,8 +7,8 @@ flash_attention — O(S·d)-HBM attention for the 32k prefill / 4k train cells
 rglru_scan — RG-LRU linear recurrence, sequential-in-time / wide-in-channels
 quantize — blockwise int8 for the compressed HierFAVG link payloads
 
-Each has a pure-jnp oracle in ref.py; ops.py is the jit'd public API with
-interpret=True off-TPU (validated on CPU, lowered on TPU).
+Each has a pure-jnp oracle in ref.py; ops.py is the public API, which runs
+the kernels interpreted on the CPU backend and compiled on TPU.
 """
 from repro.kernels import flash_attention, hier_aggregate, ops, quantize, ref, rglru_scan
 

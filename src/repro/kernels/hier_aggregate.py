@@ -16,12 +16,13 @@ sum runs in f32 on the VPU/MXU regardless of the storage dtype.
   via a (G, C, bd) reshape; no cross-block communication, perfectly
   parallel grid.
 * ``segment_mean_pallas`` — ragged groups. The sorted per-client segment
-  ids ride in as a scalar-prefetch argument (SMEM-resident, shared by all
-  grid steps; see ``docs/hierarchy.md``). The kernel builds the (G, N)
-  membership one-hot from the ids with a broadcasted iota compare and
-  reduces with two small matmuls: ``onehot @ (x*w)`` for the group sums
-  and ``onehotᵀ @ mean`` for the broadcast-back — MXU work of size
-  G×N×bd per tile, still exactly one HBM read + one HBM write of x.
+  ids ride in as one (1, N) VMEM block, fetched once and shared by all grid
+  steps (see ``docs/hierarchy.md``; Mosaic loads only scalars from SMEM, so
+  the whole vector cannot come from a scalar prefetch). The kernel builds
+  the (G, N) membership one-hot from the ids with a broadcasted iota
+  compare and reduces with two small matmuls: ``onehot @ (x*w)`` for the
+  group sums and ``onehotᵀ @ mean`` for the broadcast-back — MXU work of
+  size G×N×bd per tile, still exactly one HBM read + one HBM write of x.
   Zero-survivor groups keep their members' rows via the alive column.
 
 Grid: (ceil(D / bd),). VMEM per step: N·bd·(bytes) ≈ 32·512·4 = 64 KiB
@@ -34,7 +35,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+_EXACT = jax.lax.Precision.HIGHEST  # f32 matmuls in full f32 on the MXU, not one bf16 pass
 
 
 def _agg_kernel(x_ref, w_ref, o_ref, *, num_groups: int):
@@ -88,45 +90,53 @@ def grouped_mean_pallas(
     return out[:, :d] if pad else out
 
 
-def _segment_kernel(seg_ref, x_ref, w_ref, o_ref, *, num_segments: int):
-    """seg: (N,) int32 in SMEM; x: (N, bd) tile; w: (N, 1); o: (N, bd)."""
-    x = x_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    n, _ = x.shape
-    seg = seg_ref[...]
+def _segment_reduce(seg, x, w, num_segments: int):
+    """Segment mean of an (N, bd) f32 tile, broadcast back. seg: (1, N) int32
+    sorted ids; w: (N, 1) weights. Builds the (G, N) membership one-hot with
+    a broadcasted iota compare and reduces with two small MXU matmuls:
+    ``onehot @ (x*w)`` for the group sums and ``onehotᵀ @ mean`` back to the
+    members; members of dead groups keep their rows."""
+    n = x.shape[0]
     gids = jax.lax.broadcasted_iota(jnp.int32, (num_segments, n), 0)
-    onehot = (seg[None, :] == gids).astype(jnp.float32)  # (G, N)
-    num = jnp.dot(onehot, x * w, preferred_element_type=jnp.float32)  # (G, bd)
-    den = jnp.dot(onehot, w, preferred_element_type=jnp.float32)  # (G, 1)
+    onehot = (seg == gids).astype(jnp.float32)  # (G, N)
+    num = jnp.dot(onehot, x * w, precision=_EXACT, preferred_element_type=jnp.float32)  # (G, bd)
+    den = jnp.dot(onehot, w, precision=_EXACT, preferred_element_type=jnp.float32)  # (G, 1)
     mean = num / jnp.where(den > 0, den, 1.0)
     alive = (den > 0).astype(jnp.float32)  # (G, 1)
     # broadcast-back: members of alive groups get the mean, dead groups
     # keep their input rows (onehotᵀ @ alive is each member's liveness)
-    back = jnp.dot(onehot.T, mean * alive, preferred_element_type=jnp.float32)
-    keep = 1.0 - jnp.dot(onehot.T, alive, preferred_element_type=jnp.float32)
-    o_ref[...] = (back + x * keep).astype(o_ref.dtype)
+    back = jnp.dot(onehot.T, mean * alive, precision=_EXACT, preferred_element_type=jnp.float32)
+    keep = 1.0 - jnp.dot(onehot.T, alive, precision=_EXACT, preferred_element_type=jnp.float32)
+    return back + x * keep
 
 
-def _dequant_segment_kernel(seg_ref, q_ref, s_ref, w_ref, o_ref, *, num_segments: int, qblock: int):
-    """seg: (N,) int32 in SMEM; q: (N, bd) int8 tile; s: (N, bd/qblock) f32
-    scales; w: (N, 1); o: (N, bd) f32. Dequantize + one-hot MXU segment
-    reduction in one VMEM residency — the int8 payload is the only HBM
-    read of the stacked deltas (~¼ the f32 bytes)."""
-    qv = q_ref[...].astype(jnp.float32)  # (N, bd)
-    sv = s_ref[...]  # (N, bd/qblock)
-    n, bd = qv.shape
-    x = (qv.reshape(n, bd // qblock, qblock) * sv[..., None]).reshape(n, bd)
-    w = w_ref[...].astype(jnp.float32)
-    seg = seg_ref[...]
-    gids = jax.lax.broadcasted_iota(jnp.int32, (num_segments, n), 0)
-    onehot = (seg[None, :] == gids).astype(jnp.float32)  # (G, N)
-    num = jnp.dot(onehot, x * w, preferred_element_type=jnp.float32)  # (G, bd)
-    den = jnp.dot(onehot, w, preferred_element_type=jnp.float32)  # (G, 1)
-    mean = num / jnp.where(den > 0, den, 1.0)
-    alive = (den > 0).astype(jnp.float32)  # (G, 1)
-    back = jnp.dot(onehot.T, mean * alive, preferred_element_type=jnp.float32)
-    keep = 1.0 - jnp.dot(onehot.T, alive, preferred_element_type=jnp.float32)
-    o_ref[...] = (back + x * keep).astype(o_ref.dtype)
+def _segment_kernel(seg_ref, w_ref, x_ref, o_ref, *, num_segments: int):
+    """seg: (1, N) int32; w: (N, 1); x: (N, bd) tile; o: (N, bd)."""
+    x = x_ref[...].astype(jnp.float32)
+    out = _segment_reduce(seg_ref[...], x, w_ref[...].astype(jnp.float32), num_segments)
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _dequant_segment_kernel(seg_ref, w_ref, q_ref, s_ref, o_ref, *, num_segments: int, qblock: int):
+    """seg: (1, N) int32; w: (N, 1); q: (N, bd) int8 tile; s: (bd/qblock, N,
+    1) f32 scales, one (N, 1) column per quantization block; o: (N, bd) f32.
+    Dequantize + one-hot MXU segment reduction in one VMEM residency — the
+    int8 payload is the only HBM read of the stacked deltas (~¼ the f32
+    bytes)."""
+    # lane-aligned qblock-wide slices, each scaled by its own column
+    x = jnp.concatenate(
+        [q_ref[:, t * qblock:(t + 1) * qblock].astype(jnp.float32) * s_ref[t]
+         for t in range(s_ref.shape[0])],
+        axis=1,
+    )
+    out = _segment_reduce(seg_ref[...], x, w_ref[...].astype(jnp.float32), num_segments)
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _segment_specs(n: int):
+    """BlockSpecs of the (1, N) ids and (N, 1) weights: whole-array blocks
+    with a constant index, fetched into VMEM once for the whole grid."""
+    return [pl.BlockSpec((1, n), lambda i: (0, 0)), pl.BlockSpec((n, 1), lambda i: (0, 0))]
 
 
 def segment_dequant_mean_pallas(
@@ -168,25 +178,23 @@ def segment_dequant_mean_pallas(
     qp = jnp.pad(q, ((0, 0), (0, pad))) if pad else q
     sp = jnp.pad(scales, ((0, 0), (0, pad // qblock))) if pad else scales
     dp = d + pad
-    w2 = weights.reshape(n, 1).astype(jnp.float32)
+    # (Dp/qblock, N, 1): each quantization block's scales are one (N, 1)
+    # column, so a tile's scales arrive as a leading-dim stack (a (N, bd/qblock)
+    # block would not be (8, 128)-aligned)
+    st = sp.T[..., None]
     sblock = block_d // qblock
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(dp // block_d,),
-        in_specs=[
-            pl.BlockSpec((n, block_d), lambda i, seg_ref: (0, i)),
-            pl.BlockSpec((n, sblock), lambda i, seg_ref: (0, i)),
-            pl.BlockSpec((n, 1), lambda i, seg_ref: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, block_d), lambda i, seg_ref: (0, i)),
-    )
     out = pl.pallas_call(
         functools.partial(_dequant_segment_kernel, num_segments=num_segments, qblock=qblock),
-        grid_spec=grid_spec,
+        grid=(dp // block_d,),
+        in_specs=_segment_specs(n) + [
+            pl.BlockSpec((n, block_d), lambda i: (0, i)),
+            pl.BlockSpec((sblock, n, 1), lambda i: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((n, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, dp), jnp.float32),
         interpret=interpret,
-    )(seg, qp, sp, w2)
+    )(seg.reshape(1, n), weights.reshape(n, 1).astype(jnp.float32), qp, st)
     return out[:, :d] if pad else out
 
 
@@ -204,8 +212,8 @@ def segment_mean_pallas(
 
     Returns the per-segment weighted mean broadcast back to members, (N, D);
     zero-weight segments keep their rows. D is padded to a block multiple
-    internally. The ids travel via scalar prefetch and are resident in SMEM
-    for every grid step.
+    internally. The ids travel as one (1, N) VMEM block, resident for every
+    grid step.
     """
     n, d = x.shape
     seg = jnp.asarray(segment_ids, jnp.int32)
@@ -214,21 +222,13 @@ def segment_mean_pallas(
     pad = (-d) % block_d
     xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
     dp = d + pad
-    w2 = weights.reshape(n, 1).astype(jnp.float32)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(dp // block_d,),
-        in_specs=[
-            pl.BlockSpec((n, block_d), lambda i, seg_ref: (0, i)),
-            pl.BlockSpec((n, 1), lambda i, seg_ref: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, block_d), lambda i, seg_ref: (0, i)),
-    )
     out = pl.pallas_call(
         functools.partial(_segment_kernel, num_segments=num_segments),
-        grid_spec=grid_spec,
+        grid=(dp // block_d,),
+        in_specs=_segment_specs(n) + [pl.BlockSpec((n, block_d), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((n, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, dp), x.dtype),
         interpret=interpret,
-    )(seg, xp, w2)
+    )(seg.reshape(1, n), weights.reshape(n, 1).astype(jnp.float32), xp)
     return out[:, :d] if pad else out

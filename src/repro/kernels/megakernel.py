@@ -33,10 +33,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Upper bound on the resident edge block (params + batches + temporaries),
-# chosen for a TPU v5e core's ~16 MB VMEM with headroom for double buffering.
+# chosen under the 16 MiB default scoped-VMEM limit of a TPU v5e core.
 VMEM_BUDGET_BYTES = 12 << 20
+# The compiler's scoped-VMEM limit for the kernel: the budget plus room for
+# the pipeline's second buffer of every block.
+VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES
+_EXACT = jax.lax.Precision.HIGHEST  # f32 contractions in full f32 on the MXU
 
 
 def _interval_steps(params, xin, yin, mu, *, lr: float, momentum: float):
@@ -55,42 +60,45 @@ def _interval_steps(params, xin, yin, mu, *, lr: float, momentum: float):
     for t in range(k1):
         x = xin[:, t]  # (C, b, feat)
         r = jnp.einsum(
-            "cbf,cfo->cbo", x, params, preferred_element_type=jnp.float32
+            "cbf,cfo->cbo", x, params, precision=_EXACT,
+            preferred_element_type=jnp.float32,
         ) - yin[:, t]
-        losses.append(jnp.mean(jnp.square(r), axis=(1, 2)))
+        # (C, 1) per step, reduced one axis at a time: Mosaic cannot lower a
+        # two-axis reduction down to a 1-D vector
+        losses.append(jnp.sum(jnp.sum(jnp.square(r), axis=2), axis=1, keepdims=True) / (b * out))
         grad = (2.0 / (b * out)) * jnp.einsum(
-            "cbf,cbo->cfo", x, r, preferred_element_type=jnp.float32
+            "cbf,cbo->cfo", x, r, precision=_EXACT,
+            preferred_element_type=jnp.float32,
         )
         if momentum != 0.0:
             mu = grad + momentum * mu
             params = params - lr * mu
         else:
             params = params - lr * grad
-    return params, mu, jnp.stack(losses, axis=1)
+    return params, mu, jnp.concatenate(losses, axis=1)
 
 
 def _edge_interval_kernel(
     x_ref, xin_ref, yin_ref, w_ref, mu_ref, o_ref, loss_ref, mu_out_ref,
     *, feat: int, out: int, lr: float, momentum: float,
 ):
-    """One edge: x (C, P) client rows; xin (C, κ₁, b, feat); yin (C, κ₁, b,
-    out); w (C, 1) weights; mu (C, P). Writes the post-interval edge mean
-    broadcast to members, per-step per-client losses, and the stepped
-    momentum buffer."""
-    c = x_ref.shape[0]
-    params = x_ref[...].astype(jnp.float32).reshape(c, feat, out)
-    mu = mu_ref[...].astype(jnp.float32).reshape(c, feat, out)
+    """One edge: x (C, feat, out) client maps; xin (C, κ₁, b, feat); yin (C,
+    κ₁, b, out); w (C, 1) weights; mu (C, feat, out). Writes the post-interval
+    edge mean broadcast to members, per-step per-client losses, and the
+    stepped momentum buffer."""
+    params = x_ref[...].astype(jnp.float32)
+    mu = mu_ref[...].astype(jnp.float32)
     xin = xin_ref[...].astype(jnp.float32)
     yin = yin_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)  # (C, 1)
+    w = w_ref[:, 0].astype(jnp.float32)  # (C, 1)
     params, mu, losses = _interval_steps(
         params, xin, yin, mu, lr=lr, momentum=momentum
     )
     den = jnp.sum(w)
     mean = jnp.sum(params * w[..., None], axis=0) / den  # (feat, out)
-    o_ref[...] = jnp.broadcast_to(mean[None], params.shape).reshape(c, feat * out).astype(o_ref.dtype)
-    loss_ref[...] = losses.astype(jnp.float32)
-    mu_out_ref[...] = mu.reshape(c, feat * out).astype(mu_out_ref.dtype)
+    o_ref[...] = jnp.broadcast_to(mean[None], params.shape).astype(o_ref.dtype)
+    loss_ref[:, 0] = losses.astype(jnp.float32)
+    mu_out_ref[...] = mu.astype(mu_out_ref.dtype)
 
 
 def edge_interval_pallas(
@@ -139,30 +147,36 @@ def edge_interval_pallas(
             f"{VMEM_BUDGET_BYTES >> 20} MiB VMEM budget — shrink "
             f"clients-per-edge, κ₁·b, or the model (see docs/performance.md)"
         )
-    w2 = weights.reshape(n, 1).astype(jnp.float32)
+    # weights and losses carry a unit second-minor dim so that their blocks
+    # are whole in their last two dims for any clients-per-edge
+    w3 = weights.reshape(n, 1, 1).astype(jnp.float32)
+    # each row travels as its (feat, out) map: reshaping a flat row inside the
+    # kernel is a lane-to-sublane shape cast Mosaic cannot lower
+    maps = (n, feat, out)
 
-    outs = pl.pallas_call(
+    agg, losses, mu = pl.pallas_call(
         functools.partial(
             _edge_interval_kernel, feat=feat, out=out, lr=lr, momentum=momentum
         ),
         grid=(num_edges,),
         in_specs=[
-            pl.BlockSpec((c, p), lambda e: (e, 0)),
+            pl.BlockSpec((c, feat, out), lambda e: (e, 0, 0)),
             pl.BlockSpec((c, k1, b, feat), lambda e: (e, 0, 0, 0)),
             pl.BlockSpec((c, k1, b, out), lambda e: (e, 0, 0, 0)),
-            pl.BlockSpec((c, 1), lambda e: (e, 0)),
-            pl.BlockSpec((c, p), lambda e: (e, 0)),
+            pl.BlockSpec((c, 1, 1), lambda e: (e, 0, 0)),
+            pl.BlockSpec((c, feat, out), lambda e: (e, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((c, p), lambda e: (e, 0)),
-            pl.BlockSpec((c, k1), lambda e: (e, 0)),
-            pl.BlockSpec((c, p), lambda e: (e, 0)),
+            pl.BlockSpec((c, feat, out), lambda e: (e, 0, 0)),
+            pl.BlockSpec((c, 1, k1), lambda e: (e, 0, 0)),
+            pl.BlockSpec((c, feat, out), lambda e: (e, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, p), params.dtype),
-            jax.ShapeDtypeStruct((n, k1), jnp.float32),
-            jax.ShapeDtypeStruct((n, p), params.dtype),
+            jax.ShapeDtypeStruct(maps, params.dtype),
+            jax.ShapeDtypeStruct((n, 1, k1), jnp.float32),
+            jax.ShapeDtypeStruct(maps, params.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(params, inputs, targets, w2, mu)
-    return tuple(outs)
+    )(params.reshape(maps), inputs, targets, w3, mu.reshape(maps))
+    return agg.reshape(n, p), losses.reshape(n, k1), mu.reshape(n, p)

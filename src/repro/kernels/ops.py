@@ -1,13 +1,10 @@
-"""Public jit'd kernel API with a global interpret switch.
+"""Public kernel API; the platform alone picks how the kernels run.
 
-On CPU (this container) kernels run with interpret=True — the kernel body
-executes in Python and is validated against ref.py. On TPU the same calls
-lower to Mosaic. ``use_interpret()`` defaults to True off-TPU.
+On the CPU backend the kernel bodies run in Pallas interpret mode (Python,
+validated against ref.py); on TPU they compile to Mosaic. No other backend
+is supported, and nothing can select interpret mode on a TPU.
 """
 from __future__ import annotations
-
-import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,28 +12,17 @@ import jax.numpy as jnp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import hier_aggregate as _ha
 from repro.kernels import quantize as _qz
-from repro.kernels import ref
 from repro.kernels import rglru_scan as _rg
-
-_FORCE_INTERPRET: Optional[bool] = None
-
-
-def set_interpret(value: Optional[bool]) -> None:
-    global _FORCE_INTERPRET
-    _FORCE_INTERPRET = value
 
 
 def use_interpret() -> bool:
-    if _FORCE_INTERPRET is not None:
-        return _FORCE_INTERPRET
-    return jax.default_backend() != "tpu"
-
-
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("num_groups", "block_d"))
-def _grouped_mean_jit(x, w, num_groups, block_d, interpret):
-    return _ha.grouped_mean_pallas(x, w, num_groups, block_d=block_d, interpret=interpret)
+    """True on the CPU backend, False on TPU, an error anywhere else."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run compiled on TPU or interpreted on CPU, not on {backend!r}")
 
 
 def grouped_mean(x, weights, num_groups, *, block_d: int = 512):

@@ -26,8 +26,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, hT_ref, carry, *, seq_len: int):
-    """a,b: (1, S, bd); h0: (1, bd); o: (1, S, bd); hT: (1, bd)."""
-    carry[...] = h0_ref[...].astype(jnp.float32)
+    """a,b: (1, S, bd); h0: (1, 1, bd); o: (1, S, bd); hT: (1, 1, bd)."""
+    carry[...] = h0_ref[0].astype(jnp.float32)
 
     def body(t, _):
         a_t = a_ref[0, t].astype(jnp.float32)
@@ -38,7 +38,7 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, hT_ref, carry, *, seq_len: int):
         return 0
 
     jax.lax.fori_loop(0, seq_len, body, 0)
-    hT_ref[...] = carry[...].astype(hT_ref.dtype)
+    hT_ref[0] = carry[...].astype(hT_ref.dtype)
 
 
 def rglru_scan_pallas(
@@ -61,6 +61,10 @@ def rglru_scan_pallas(
         b = jnp.pad(b, ((0, 0), (0, 0), (0, pad)))
         h0 = jnp.pad(h0, ((0, 0), (0, pad)))
     Dp = D + pad
+    # h0/hT travel as (B, 1, Dp) so their (1, block_d) blocks span the whole
+    # second-minor dim ((1, block_d) blocks of a (B, Dp) array are not
+    # (8, 128)-aligned for B > 1)
+    h0 = h0.reshape(B, 1, Dp)
 
     h, hT = pl.pallas_call(
         functools.partial(_rglru_kernel, seq_len=S),
@@ -68,19 +72,20 @@ def rglru_scan_pallas(
         in_specs=[
             pl.BlockSpec((1, S, block_d), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, S, block_d), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, S, block_d), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, Dp), jnp.float32),
-            jax.ShapeDtypeStruct((B, Dp), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, Dp), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
     )(a, b, h0)
+    hT = hT.reshape(B, Dp)
     if pad:
         return h[..., :D], hT[..., :D]
     return h, hT

@@ -12,8 +12,6 @@ from repro.fed import transport as tp
 from repro.kernels import ops, ref
 from repro.optim import compression
 
-ops.set_interpret(True)
-
 
 def make_tree(rng, scale=2.0):
     return {

@@ -80,6 +80,24 @@ def test_token_corpus_class_structure(rng):
     assert corp.tokens.max() < 50 and corp.tokens.min() >= 0
 
 
+def test_token_corpus_large_vocab_sparse_teacher(rng):
+    """Past DENSE_VOCAB_MAX each teacher row keeps SPARSE_SUCCESSORS
+    successors: a 32k vocab stays cheap, and every transition lands in its
+    (class, token) row's successor set."""
+    from repro.data import synthetic
+
+    vocab = 32768
+    corp = token_corpus(rng, num_sequences=32, seq_len=16, vocab=vocab, num_classes=2)
+    assert corp.tokens.shape == (32, 17)
+    assert corp.tokens.max() < vocab and corp.tokens.min() >= 0
+    # the same (class, token) state only ever steps to a bounded successor set
+    nxt = {}
+    for seq, label in zip(corp.tokens, corp.labels):
+        for a, b in zip(seq[:-1], seq[1:]):
+            nxt.setdefault((label, a), set()).add(b)
+    assert max(len(v) for v in nxt.values()) <= synthetic.SPARSE_SUCCESSORS
+
+
 def test_batcher_restart_safety(rng):
     x = rng.normal(size=(200, 3)).astype(np.float32)
     y = rng.integers(0, 10, size=200).astype(np.int32)

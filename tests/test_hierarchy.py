@@ -15,8 +15,6 @@ from repro.kernels import ops, ref
 from repro.optim import sgd
 from repro.testing import given, settings, st
 
-ops.set_interpret(True)
-
 
 def random_spec(seed: int, depth: int, max_fan: int = 5) -> HierarchySpec:
     """A random ragged tree: bottom-up fan-outs with 1..max_fan children."""

@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: pallas(interpret=True) vs ref.py oracle."""
+"""Per-kernel shape/dtype sweeps: pallas(interpret=True) vs ref.py oracle,
+and the platform rule that picks interpret mode."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,7 +7,18 @@ import pytest
 
 from repro.kernels import ops, ref
 
-ops.set_interpret(True)
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_follows_the_platform(monkeypatch, backend, interpret):
+    """Interpreted on CPU, compiled on TPU, refused anywhere else: nothing
+    else can select interpret mode."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=backend):
+            ops.use_interpret()
+    else:
+        assert ops.use_interpret() is interpret
+    assert not hasattr(ops, "set_interpret")
 
 
 @pytest.mark.parametrize("n,groups", [(4, 2), (8, 4), (16, 2), (32, 8)])

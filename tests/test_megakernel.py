@@ -23,13 +23,6 @@ from repro.models import cnn
 from repro.optim import adam, momentum, sgd
 
 
-@pytest.fixture(autouse=True)
-def _interpret():
-    ops.set_interpret(True)
-    yield
-    ops.set_interpret(None)
-
-
 # ---------------------------------------------------------------------------
 # Pallas kernel vs oracle
 # ---------------------------------------------------------------------------

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (
@@ -391,9 +391,9 @@ def test_sharded_edge_sync_bitexact():
     padded = _pad_state(state, placement, 12)
     specs = fed_state_partition_specs(padded, "clients", placement.padded_clients)
     with mesh:
-        out = shard_map(
+        out = jax.shard_map(
             lambda s: sync(s), mesh=mesh, in_specs=(specs,), out_specs=specs,
-            check_rep=False,
+            check_vma=False,
         )(padded)
     got = np.asarray(out.params["w"])[placement.positions()]
     np.testing.assert_array_equal(np.asarray(ref), got)
@@ -518,6 +518,21 @@ def test_runner_mesh_unshardable_falls_back_per_round():
     assert runner._engine is None  # fell back: no superround engine built
     assert runner._mesh_reason and "top-level" in runner._mesh_reason
     assert [r.round for r in runner.history] == list(range(6))
+
+
+@pytest.mark.parametrize("shards", [1, pytest.param(4, marks=needs4)])
+def test_runner_mesh_state_stays_on_the_mesh(shards):
+    """The state a mesh run hands back (canonical client order) is still
+    laid out over the mesh, client rows split across it — never committed
+    to one device (a state sharded for lack of room must not be gathered)."""
+    runner, state = _mesh_spec([f"topology.mesh_axes=clients:{shards}"]).run_experiment()
+    assert runner._engine is not None and runner._engine.mesh is not None
+    n = runner.topology.num_clients
+    for leaf in jax.tree_util.tree_leaves((state.params, state.opt_state)):
+        assert isinstance(leaf.sharding, NamedSharding) and leaf.sharding.mesh == runner.mesh
+        if leaf.ndim and leaf.shape[0] == n:
+            assert leaf.sharding.spec == P("clients")
+            assert {sh.data.shape[0] for sh in leaf.addressable_shards} == {n // shards}
 
 
 def test_topology_spec_mesh_axes_roundtrip_and_errors():

@@ -498,6 +498,21 @@ def _cohort_spec(extra=()):
     )
 
 
+@pytest.mark.parametrize("shards", [1, pytest.param(4, marks=needs4)])
+def test_cohort_runner_mesh_state_stays_on_the_mesh(shards):
+    """The cohort state a mesh run hands back (canonical slot order) stays
+    laid out over the mesh, slot rows split across it."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    runner, state = _cohort_spec([f"topology.mesh_axes=clients:{shards}"]).run_experiment()
+    assert runner._engine is not None and runner._engine.mesh is not None
+    c = runner.participation.cohort_size
+    for leaf in jax.tree_util.tree_leaves((state.params, state.opt_state)):
+        assert isinstance(leaf.sharding, NamedSharding) and leaf.sharding.mesh == runner.mesh
+        if leaf.ndim and leaf.shape[0] == c:
+            assert leaf.sharding.spec == PartitionSpec("clients")
+
+
 def test_cohort_runner_mesh_requires_stratified():
     """A mesh + a non-stratified sampler is a named hard error (no silent
     downgrade — sampled participation has no per-round fallback)."""
