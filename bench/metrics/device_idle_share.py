@@ -1,0 +1,8 @@
+"""device_idle_share: the share of the traced window in which no operation
+ran on the chip (the union of the op intervals), mean over the cell's chips,
+in percent."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
