@@ -1,0 +1,10 @@
+"""step_backward_ms: per cloud interval, the milliseconds of device self time
+in the backward pass of the local step: ops under the program's
+``hierfavg.local_step.grad`` scope with ``transpose(`` on their path, mean
+over the cell's chips (``bench/scopes.py``). Nothing to read where no op
+carries a scope."""
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.phase_ms(ctx, "step_backward_ms")

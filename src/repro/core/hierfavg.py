@@ -30,6 +30,12 @@ Two driving modes are exposed:
 Topology arguments accept either the seed's two-level ``FedTopology`` or a
 ragged ``core.hierarchy.HierarchySpec``; the former is the
 ``levels=2, uniform`` special case with unchanged numerics.
+
+Every lowering names its phases with ``jax.named_scope``:
+``hierfavg.local_step.{grad,optimizer,grad_norm}``,
+``hierfavg.sync.{edge,l<k>,cloud}`` and ``hierfavg.codec``. The scopes are
+op metadata only; profiles and per-layer metrics read them
+(docs/performance.md, "Profiling a run").
 """
 from __future__ import annotations
 
@@ -406,12 +412,13 @@ def build_local_step(
         rng, step_rng = jax.random.split(state.rng)
         n = jax.tree_util.tree_leaves(state.params)[0].shape[0]
         rngs = jax.random.split(step_rng, n)
-        grads, losses = microbatch_grads(state.params, batch, rngs)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = apply_updates(state.params, updates)
-        gnorm = jnp.sqrt(
-            sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree_util.tree_leaves(grads))
+        params, opt_state, grads, losses = _local_update(
+            microbatch_grads, optimizer, state.params, state.opt_state, batch, rngs
         )
+        with jax.named_scope("hierfavg.local_step.grad_norm"):
+            gnorm = jnp.sqrt(
+                sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree_util.tree_leaves(grads))
+            )
         metrics = {"loss": jnp.mean(losses.astype(jnp.float32)), "grad_norm": gnorm}
         return (
             FedState(
@@ -422,6 +429,78 @@ def build_local_step(
         )
 
     return local_step
+
+
+def _local_update(microbatch_grads, optimizer, params, opt_state, batch, rngs):
+    """Every client's gradients (forward and backward, under the scope
+    ``hierfavg.local_step.grad``) and the optimizer step that applies them
+    (``hierfavg.local_step.optimizer``); returns (params, opt_state, grads,
+    per-client losses). The scopes name the phases in HLO metadata and
+    device traces and change no op."""
+    with jax.named_scope("hierfavg.local_step.grad"):
+        grads, losses = microbatch_grads(params, batch, rngs)
+    with jax.named_scope("hierfavg.local_step.optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        params = apply_updates(params, updates)
+    return params, opt_state, grads, losses
+
+
+def _per_client_grad_sq(grads) -> jnp.ndarray:
+    """(N,) squared gradient norm of each client, in f32."""
+    with jax.named_scope("hierfavg.local_step.grad_norm"):
+        return sum(
+            jnp.sum(jnp.square(g.astype(jnp.float32)), axis=tuple(range(1, g.ndim)))
+            for g in jax.tree_util.tree_leaves(grads)
+        )
+
+
+def _build_sharded_local_step(microbatch_grads, optimizer):
+    """The local step inside a client-sharded ``shard_map`` body: the keys
+    arrive precomputed, and the metrics stay per client (losses and squared
+    gradient norms), reduced host-side."""
+
+    def local_step(s: FedState, batch: PyTree, rngs):
+        params, opt_state, grads, losses = _local_update(
+            microbatch_grads, optimizer, s.params, s.opt_state, batch, rngs
+        )
+        return (
+            FedState(
+                step=s.step + 1, params=params, opt_state=opt_state, rng=s.rng,
+                anchor=s.anchor, residual=s.residual,
+            ),
+            losses.astype(jnp.float32),
+            _per_client_grad_sq(grads),
+        )
+
+    return local_step
+
+
+def _sync_scope(level: int, depth: int) -> str:
+    """The named scope of a level's sync: ``hierfavg.sync.edge`` (level 1),
+    ``hierfavg.sync.cloud`` (the top level, and so also a one-level
+    schedule's only sync), ``hierfavg.sync.l<k>`` between them."""
+    if level == depth:
+        return "hierfavg.sync.cloud"
+    if level == 1:
+        return "hierfavg.sync.edge"
+    return f"hierfavg.sync.l{level}"
+
+
+def _codec_upload(codec, state: FedState):
+    """What a client's upload delivers through ``codec``: its anchor plus
+    the encode∘decode of its delta from the anchor (scope
+    ``hierfavg.codec``); returns (uploaded params, new EF residual)."""
+    with jax.named_scope("hierfavg.codec"):
+        delta = jax.tree_util.tree_map(
+            lambda x, a: x.astype(jnp.float32) - a.astype(jnp.float32),
+            state.params, state.anchor,
+        )
+        delta_hat, residual = codec.roundtrip(delta, state.residual)
+        uploaded = jax.tree_util.tree_map(
+            lambda a, d, x: (a.astype(jnp.float32) + d).astype(x.dtype),
+            state.anchor, delta_hat, state.params,
+        )
+    return uploaded, residual
 
 
 def _maybe_sync_opt_state(opt_state, agg_fn, sync: bool):
@@ -612,15 +691,7 @@ def build_level_sync(
         uploaded = state.params
         residual = state.residual
         if codec is not None:
-            delta = jax.tree_util.tree_map(
-                lambda x, a: x.astype(jnp.float32) - a.astype(jnp.float32),
-                state.params, state.anchor,
-            )
-            delta_hat, residual = codec.roundtrip(delta, residual)
-            uploaded = jax.tree_util.tree_map(
-                lambda a, d, x: (a.astype(jnp.float32) + d).astype(x.dtype),
-                state.anchor, delta_hat, state.params,
-            )
+            uploaded, residual = _codec_upload(codec, state)
         if is_top and config.delta_cloud and state.anchor is not None:
             agg = lambda t: aggregation.delta_weighted_mean(t, state.anchor, weights, mask)
             params = agg(uploaded)
@@ -666,7 +737,7 @@ def build_level_sync(
         opt_state = _maybe_sync_opt_state(state.opt_state, agg, config.sync_opt_state)
         return state._replace(params=params, opt_state=opt_state, anchor=anchor, residual=residual)
 
-    return level_sync
+    return jax.named_scope(_sync_scope(level, spec.depth))(level_sync)
 
 
 def _build_sharded_level_sync(spec, config, level, codec, robust, shard: ClientSharding):
@@ -705,15 +776,7 @@ def _build_sharded_level_sync(spec, config, level, codec, robust, shard: ClientS
         uploaded = state.params
         residual = state.residual
         if codec is not None:
-            delta = jax.tree_util.tree_map(
-                lambda x, a: x.astype(jnp.float32) - a.astype(jnp.float32),
-                state.params, state.anchor,
-            )
-            delta_hat, residual = codec.roundtrip(delta, residual)
-            uploaded = jax.tree_util.tree_map(
-                lambda a, d, x: (a.astype(jnp.float32) + d).astype(x.dtype),
-                state.anchor, delta_hat, state.params,
-            )
+            uploaded, residual = _codec_upload(codec, state)
         agg = None  # per-tree closure (sub-top opt_state sync)
         synced_opt = None  # opt_state that rode the top-level packed psum
         alive_top = None
@@ -793,7 +856,7 @@ def _build_sharded_level_sync(spec, config, level, codec, robust, shard: ClientS
             opt_state = _maybe_sync_opt_state(state.opt_state, agg, config.sync_opt_state)
         return state._replace(params=params, opt_state=opt_state, anchor=anchor, residual=residual)
 
-    return level_sync
+    return jax.named_scope(_sync_scope(level, depth))(level_sync)
 
 
 def build_edge_sync(topology: Topology, config: HierFAVGConfig, weights: jnp.ndarray):
@@ -1069,6 +1132,7 @@ def build_deadline_super_round(
     level_syncs = [build_level_sync(spec, config, weights, l) for l in range(1, depth)]
     deepest_per_round = jnp.asarray(super_round_schedule(config), jnp.int32)
 
+    @jax.named_scope("hierfavg.sync.cloud")
     def gated_top_sync(state: FedState, mask_r, gate) -> FedState:
         # staged composition, mirroring hierarchical_segment_mean(..., depth):
         # sub-top stages with the survival mask alone (every edge syncs),
@@ -1274,16 +1338,9 @@ def build_megakernel_super_round(
         losses_t, gsq_t = [], []
         for t in range(k1):
             batch_t = tmap(lambda x: x[t], batches)
-            grads, losses = grad_fn(params, batch_t, rngs[t])
-            updates, opt = optimizer.update(grads, opt, params)
-            params = apply_updates(params, updates)
+            params, opt, grads, losses = _local_update(grad_fn, optimizer, params, opt, batch_t, rngs[t])
             losses_t.append(losses.astype(jnp.float32))
-            gsq_t.append(
-                sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)), axis=tuple(range(1, g.ndim)))
-                    for g in tleaves(grads)
-                )
-            )
+            gsq_t.append(_per_client_grad_sq(grads))
         return params, opt, jnp.stack(losses_t), jnp.stack(gsq_t)
 
     def super_round(state: FedState, batches: PyTree, masks: Optional[jnp.ndarray] = None):
@@ -1342,10 +1399,12 @@ def build_megakernel_super_round(
             ls = jnp.moveaxis(losses, 0, 1).reshape(k1, n)
             gs = jnp.moveaxis(gsq, 0, 1).reshape(k1, n)
             loss_r.append(jnp.mean(ls))
-            gnorm_r.append(jnp.mean(jnp.sqrt(jnp.sum(gs, axis=1))))
+            with jax.named_scope("hierfavg.local_step.grad_norm"):
+                gnorm_r.append(jnp.mean(jnp.sqrt(jnp.sum(gs, axis=1))))
             step_r.append(step0 + (j + 1) * k1)
             mean_leaf = cloud_mean_leaf if deepest_per_round[j] == 2 else edge_mean_leaf
-            params = tmap(mean_leaf, params)
+            with jax.named_scope(_sync_scope(deepest_per_round[j], 2)):
+                params = tmap(mean_leaf, params)
         new_state = FedState(
             step=step0 + k1 * k2, params=params, opt_state=opt_state, rng=rng,
             anchor=state.anchor, residual=state.residual,
@@ -1458,15 +1517,7 @@ def _build_cohort_level_sync(spec: HierarchySpec, config: HierFAVGConfig, level:
         uploaded = state.params
         residual = state.residual
         if codec is not None:
-            delta = jax.tree_util.tree_map(
-                lambda x, a: x.astype(jnp.float32) - a.astype(jnp.float32),
-                state.params, state.anchor,
-            )
-            delta_hat, residual = codec.roundtrip(delta, residual)
-            uploaded = jax.tree_util.tree_map(
-                lambda a, d, x: (a.astype(jnp.float32) + d).astype(x.dtype),
-                state.anchor, delta_hat, state.params,
-            )
+            uploaded, residual = _codec_upload(codec, state)
         if is_top and config.delta_cloud and state.anchor is not None:
             agg = lambda t: aggregation.delta_weighted_mean(t, state.anchor, cohort["weights"], mask)
             params = agg(uploaded)
@@ -1507,7 +1558,7 @@ def _build_cohort_level_sync(spec: HierarchySpec, config: HierFAVGConfig, level:
         opt_state = _maybe_sync_opt_state(state.opt_state, agg, config.sync_opt_state)
         return state._replace(params=params, opt_state=opt_state, anchor=anchor, residual=residual)
 
-    return level_sync
+    return jax.named_scope(_sync_scope(level, depth))(level_sync)
 
 
 def build_cohort_super_round(
@@ -1667,7 +1718,9 @@ def build_sharded_super_round(
     if reason is not None:
         raise ValueError(f"schedule cannot run client-sharded: {reason}")
     shard = ClientSharding.build(axis, placement, weights)
-    microbatch_grads = _build_microbatch_grads(_apply_precision(loss_fn, config.precision), grad_accum)
+    local_step = _build_sharded_local_step(
+        _build_microbatch_grads(_apply_precision(loss_fn, config.precision), grad_accum), optimizer
+    )
     level_syncs = [
         build_level_sync(spec, config, weights, lvl, shard=shard) for lvl in range(1, depth + 1)
     ]
@@ -1675,23 +1728,6 @@ def build_sharded_super_round(
     ids_table = shard.client_ids_table()
     n_real = spec.num_clients
     n_padded = placement.padded_clients
-
-    def local_step(s: FedState, batch: PyTree, rngs):
-        grads, losses = microbatch_grads(s.params, batch, rngs)
-        updates, opt_state = optimizer.update(grads, s.opt_state, s.params)
-        params = apply_updates(s.params, updates)
-        gsq = sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)), axis=tuple(range(1, g.ndim)))
-            for g in jax.tree_util.tree_leaves(grads)
-        )
-        return (
-            FedState(
-                step=s.step + 1, params=params, opt_state=opt_state, rng=s.rng,
-                anchor=s.anchor, residual=s.residual,
-            ),
-            losses.astype(jnp.float32),
-            gsq,
-        )
 
     def body(state: FedState, batches: PyTree, masks):
         ids = _shard_row(ids_table, axis)
@@ -1909,7 +1945,9 @@ def build_sharded_cohort_super_round(
     if placement is None:
         placement = plan_cohort_placement(spec, _cohort_quotas(spec, cohort_size), num_shards)
     shard = _CohortSharding(axis=axis, placement=placement, weights_table=[None])
-    microbatch_grads = _build_microbatch_grads(_apply_precision(loss_fn, config.precision), grad_accum)
+    local_step = _build_sharded_local_step(
+        _build_microbatch_grads(_apply_precision(loss_fn, config.precision), grad_accum), optimizer
+    )
     level_syncs = []
     for lvl in range(1, depth + 1):
         codec = None
@@ -1926,23 +1964,6 @@ def build_sharded_cohort_super_round(
     slots_table = shard.client_ids_table()  # (num_shards, capacity) slot ids
     c = int(cohort_size)
     c_padded = placement.padded_clients
-
-    def local_step(s: FedState, batch: PyTree, rngs):
-        grads, losses = microbatch_grads(s.params, batch, rngs)
-        updates, opt_state = optimizer.update(grads, s.opt_state, s.params)
-        params = apply_updates(s.params, updates)
-        gsq = sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)), axis=tuple(range(1, g.ndim)))
-            for g in jax.tree_util.tree_leaves(grads)
-        )
-        return (
-            FedState(
-                step=s.step + 1, params=params, opt_state=opt_state, rng=s.rng,
-                anchor=s.anchor, residual=s.residual,
-            ),
-            losses.astype(jnp.float32),
-            gsq,
-        )
 
     def body(state: FedState, batches: PyTree, weights, masks):
         shard.bind_local_weights(weights)

@@ -266,6 +266,12 @@ class SuperBatchPrefetcher:
     single-device copy; ``transform`` is an optional host-side (numpy) hook
     applied to the assembled block before upload — the engine uses it to
     permute + pad the client axis into shard placement order.
+
+    Tracing: the worker's host work is named ``data.block_gather`` and
+    ``data.block_upload`` (the latter with the block's ``bytes``) in profiler
+    traces; both carry ``interval``, the cloud interval that consumes the
+    block (``first_interval`` plus the blocks made before it), as the
+    engine's ``fed.*`` spans do.
     """
 
     _SENTINEL_OK = "ok"
@@ -282,6 +288,7 @@ class SuperBatchPrefetcher:
         prefetch: int = 1,
         use_thread: bool = True,
         transform: Optional[Callable[[PyTree], PyTree]] = None,
+        first_interval: int = 0,
     ):
         self.batcher = batcher
         self.rounds_per_block = int(rounds_per_block)
@@ -289,6 +296,8 @@ class SuperBatchPrefetcher:
         self.num_blocks = num_blocks
         self.device = device
         self.transform = transform
+        self.first_interval = int(first_interval)
+        self._block_bytes: Optional[int] = None  # host bytes of one block, read once
         self._produced = 0
         self._consumed = 0
         self._use_thread = use_thread
@@ -301,19 +310,30 @@ class SuperBatchPrefetcher:
             self._thread.start()
 
     # -- block production ----------------------------------------------------
+    def _upload_bytes(self, tree: PyTree) -> int:
+        if self._block_bytes is None:  # every block has the same shapes
+            import jax
+
+            self._block_bytes = sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+        return self._block_bytes
+
     def _make_block(self) -> Tuple[PyTree, Dict[str, Any]]:
         import jax
+        from jax.profiler import TraceAnnotation
 
-        flat = self.batcher.next_batches(self.rounds_per_block * self.steps_per_round)
-        block = jax.tree_util.tree_map(
-            lambda x: np.reshape(
-                x, (self.rounds_per_block, self.steps_per_round) + x.shape[1:]
-            ),
-            flat,
-        )
-        if self.transform is not None:
-            block = self.transform(block)
-        block = jax.device_put(block, self.device)  # async upload
+        interval = self.first_interval + self._produced
+        with TraceAnnotation("data.block_gather", interval=interval):
+            flat = self.batcher.next_batches(self.rounds_per_block * self.steps_per_round)
+            block = jax.tree_util.tree_map(
+                lambda x: np.reshape(
+                    x, (self.rounds_per_block, self.steps_per_round) + x.shape[1:]
+                ),
+                flat,
+            )
+            if self.transform is not None:
+                block = self.transform(block)
+        with TraceAnnotation("data.block_upload", interval=interval, bytes=self._upload_bytes(block)):
+            block = jax.device_put(block, self.device)  # async upload
         snapshot = self.batcher.state_dict()
         return block, snapshot
 
@@ -334,6 +354,12 @@ class SuperBatchPrefetcher:
             self._queue.put((self._SENTINEL_ERR, e, None))
 
     # -- consumption ---------------------------------------------------------
+    @property
+    def ready(self) -> bool:
+        """Whether the next block is already queued, so ``get()`` will not
+        wait (always False without the worker thread)."""
+        return self._use_thread and not self._queue.empty()
+
     def get(self) -> Tuple[PyTree, Dict[str, Any]]:
         """Next (device_block, batcher_state_snapshot). Blocks until ready."""
         if self.num_blocks is not None and self._consumed >= self.num_blocks:
@@ -410,6 +436,7 @@ class CohortPrefetcher(SuperBatchPrefetcher):
         use_thread: bool = True,
         placement=None,
         weights_device=None,
+        first_interval: int = 0,
     ):
         # fields first: the base __init__ starts the worker thread, which
         # calls our _make_block immediately
@@ -432,33 +459,41 @@ class CohortPrefetcher(SuperBatchPrefetcher):
             device=device,
             prefetch=prefetch,
             use_thread=use_thread,
+            first_interval=first_interval,
         )
 
     def _make_block(self):
         import jax
+        from jax.profiler import TraceAnnotation
 
-        ids = np.asarray(self.sampler.sample(), np.int64)
-        flat = self.batcher.next_batches_for(ids, self.rounds_per_block * self.steps_per_round)
-        block = jax.tree_util.tree_map(
-            lambda x: np.reshape(
-                x, (self.rounds_per_block, self.steps_per_round) + x.shape[1:]
-            ),
-            flat,
-        )
-        if self._placement is not None:
-            # slot placement order: phantom slots replicate slot 0's batch
-            # (their weight is zero), matching the sharded superround's pad
-            gather = self._placement.gather_index()
-            block = jax.tree_util.tree_map(lambda x: x[:, :, gather], block)
-            cohort = {"weights": self._placement.pad_weights(self._weights[ids])}
-            block = jax.device_put(block, self.device)  # async per-device upload
-            cohort = jax.device_put(cohort, self._weights_device)
-        else:
-            cohort = {
-                "segments": self._segments[:, ids],
-                "weights": self._weights[ids],
-            }
-            cohort, block = jax.device_put((cohort, block), self.device)  # async upload
+        interval = self.first_interval + self._produced
+        with TraceAnnotation("data.cohort_sample", interval=interval):
+            ids = np.asarray(self.sampler.sample(), np.int64)
+        with TraceAnnotation("data.block_gather", interval=interval):
+            flat = self.batcher.next_batches_for(ids, self.rounds_per_block * self.steps_per_round)
+            block = jax.tree_util.tree_map(
+                lambda x: np.reshape(
+                    x, (self.rounds_per_block, self.steps_per_round) + x.shape[1:]
+                ),
+                flat,
+            )
+            if self._placement is not None:
+                # slot placement order: phantom slots replicate slot 0's batch
+                # (their weight is zero), matching the sharded superround's pad
+                gather = self._placement.gather_index()
+                block = jax.tree_util.tree_map(lambda x: x[:, :, gather], block)
+                cohort = {"weights": self._placement.pad_weights(self._weights[ids])}
+            else:
+                cohort = {
+                    "segments": self._segments[:, ids],
+                    "weights": self._weights[ids],
+                }
+        with TraceAnnotation("data.block_upload", interval=interval, bytes=self._upload_bytes((cohort, block))):
+            if self._placement is not None:
+                block = jax.device_put(block, self.device)  # async per-device upload
+                cohort = jax.device_put(cohort, self._weights_device)
+            else:
+                cohort, block = jax.device_put((cohort, block), self.device)  # async upload
         snapshot = {
             "batcher": self.batcher.state_dict(),
             "sampler": self.sampler.state_dict(),

@@ -25,6 +25,13 @@ removes every per-round host cost:
 * **Device-side batch prefetch** — a ``data.pipeline.SuperBatchPrefetcher``
   worker assembles and ``jax.device_put``s interval r+1's
   (κ₂, κ₁, N, b, ...) block while interval r computes.
+* **Named host spans** — ``fed.run``, ``fed.interval`` (a step annotation
+  per cloud interval) and inside it ``fed.prefetch_wait``,
+  ``fed.dispatch``, ``fed.flush``, ``fed.eval``, ``fed.checkpoint`` (and
+  the cohort engine's ``fed.store_load`` / ``fed.store_writeback``), each
+  with its ``interval``, put the loop on the profiler's clock next to the
+  device ops (docs/performance.md, "Profiling a run"). With the profiler
+  off each costs one ``TraceMe`` check and no host sync.
 
 **Mesh execution** — when the runner carries a device mesh, the engine
 swaps in ``core.hierfavg.build_sharded_super_round``: the stacked client
@@ -51,6 +58,7 @@ from typing import Any, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import aggregation
 from repro.core.hierarchy import as_hierarchy, plan_shard_placement
@@ -285,6 +293,10 @@ class SuperRoundEngine:
         ``start_round``. Takes and returns canonical client order (the mesh
         path converts to placement order internally). Returns
         (state, stopped_early)."""
+        with TraceAnnotation("fed.run", interval=start_round // self.k2, count=num_intervals):
+            return self._run_intervals(state, start_round=start_round, num_intervals=num_intervals)
+
+    def _run_intervals(self, state: FedState, *, start_round: int, num_intervals: int):
         r = self.runner
         if start_round % self.k2:
             raise ValueError(
@@ -309,6 +321,7 @@ class SuperRoundEngine:
             is FederatedRunner._mask_for_round
         )
         static_masks = (None, [r.topology.num_clients] * self.k2, None)
+        first = start_round // self.k2  # the cloud interval the call starts at
         prefetcher = SuperBatchPrefetcher(
             r.batcher,
             rounds_per_block=self.k2,
@@ -317,47 +330,57 @@ class SuperRoundEngine:
             device=self._block_sharding if self.mesh is not None else None,
             use_thread=self.prefetch,
             transform=self._block_transform(),
+            first_interval=first,
         )
+        interval = first
         try:
             for q in range(num_intervals):
+                interval = first + q
                 round_base = start_round + q * self.k2
-                block, batcher_snapshot = prefetcher.get()
-                mask_stack, alive, last_mask = (
-                    static_masks if no_failures else self._masks_for_interval()
-                )
-                state, metrics = self._dispatch_interval(state, block, mask_stack, round_base)
-                self._pending.append((round_base, alive, metrics))
+                with StepTraceAnnotation("fed.interval", step_num=interval):
+                    with TraceAnnotation("fed.prefetch_wait", interval=interval, ready=prefetcher.ready):
+                        block, batcher_snapshot = prefetcher.get()
+                    mask_stack, alive, last_mask = (
+                        static_masks if no_failures else self._masks_for_interval()
+                    )
+                    with TraceAnnotation("fed.dispatch", interval=interval):
+                        state, metrics = self._dispatch_interval(state, block, mask_stack, round_base)
+                    self._pending.append((round_base, alive, metrics))
 
-                end_round = round_base + self.k2  # rounds completed so far
-                do_eval = (
-                    r.eval_fn is not None
-                    and r.cfg.eval_every
-                    and end_round % r.cfg.eval_every == 0
-                )
-                do_ckpt = (
-                    r.checkpointer is not None
-                    and r.cfg.checkpoint_every
-                    and end_round % r.cfg.checkpoint_every == 0
-                )
-                if do_eval or do_ckpt:
-                    self._flush(wire_per_step)
-                acc = None
-                if do_eval:
-                    mask_eval = self._eval_mask(last_mask)
-                    mask_last = None if mask_eval is None else jnp.asarray(mask_eval)
-                    cloud0 = r.eval_model(self._canonical_params(state), mask_last)
-                    acc = float(r.eval_fn(cloud0))
-                    r.history[-1].accuracy = acc
-                if do_ckpt:
-                    # the live batcher has prefetched ahead; the snapshot is
-                    # the cursor state as of THIS block's cloud boundary
-                    meta = self._checkpoint_meta(end_round, batcher_snapshot)
-                    save_state = state if self.mesh is None else self._unshard_state(state)
-                    r.checkpointer.save(r.history[-1].step, save_state, meta)
+                    end_round = round_base + self.k2  # rounds completed so far
+                    do_eval = (
+                        r.eval_fn is not None
+                        and r.cfg.eval_every
+                        and end_round % r.cfg.eval_every == 0
+                    )
+                    do_ckpt = (
+                        r.checkpointer is not None
+                        and r.cfg.checkpoint_every
+                        and end_round % r.cfg.checkpoint_every == 0
+                    )
+                    if do_eval or do_ckpt:
+                        with TraceAnnotation("fed.flush", interval=interval):
+                            self._flush(wire_per_step)
+                    acc = None
+                    if do_eval:
+                        with TraceAnnotation("fed.eval", interval=interval):
+                            mask_eval = self._eval_mask(last_mask)
+                            mask_last = None if mask_eval is None else jnp.asarray(mask_eval)
+                            cloud0 = r.eval_model(self._canonical_params(state), mask_last)
+                            acc = float(r.eval_fn(cloud0))
+                        r.history[-1].accuracy = acc
+                    if do_ckpt:
+                        with TraceAnnotation("fed.checkpoint", interval=interval):
+                            # the live batcher has prefetched ahead; the snapshot is
+                            # the cursor state as of THIS block's cloud boundary
+                            meta = self._checkpoint_meta(end_round, batcher_snapshot)
+                            save_state = state if self.mesh is None else self._unshard_state(state)
+                            r.checkpointer.save(r.history[-1].step, save_state, meta)
                 if acc is not None and r.cfg.target_accuracy and acc >= r.cfg.target_accuracy:
                     stopped = True
                     break
-            self._flush(wire_per_step)
+            with TraceAnnotation("fed.flush", interval=interval):
+                self._flush(wire_per_step)
         finally:
             prefetcher.stop()
         if self.mesh is not None:
@@ -700,6 +723,10 @@ class CohortEngine:
     ) -> Tuple[FedState, bool]:
         """Run ``num_intervals`` cloud intervals from a cloud-aligned
         ``start_round``. Returns (state, stopped_early)."""
+        with TraceAnnotation("fed.run", interval=start_round // self.k2, count=num_intervals):
+            return self._run_intervals(state, start_round=start_round, num_intervals=num_intervals)
+
+    def _run_intervals(self, state: FedState, *, start_round: int, num_intervals: int):
         r = self.runner
         if start_round % self.k2:
             raise ValueError(
@@ -724,6 +751,7 @@ class CohortEngine:
             is FederatedRunner._mask_for_round
         )
         static_masks = (None, [self.cohort_size] * self.k2, None)
+        first = start_round // self.k2  # the cloud interval the call starts at
         prefetcher = CohortPrefetcher(
             r.batcher,
             r._cohort_sampler(),
@@ -736,66 +764,78 @@ class CohortEngine:
             use_thread=self.prefetch,
             placement=self.placement,
             weights_device=self._row_sharding if self.mesh is not None else None,
+            first_interval=first,
         )
+        interval = first
         try:
             for q in range(num_intervals):
+                interval = first + q
                 round_base = start_round + q * self.k2
-                (ids, cohort, block), snapshot = prefetcher.get()
-                mask_dev, alive, last_mask = (
-                    static_masks if no_failures else self._masks_for_interval(ids)
-                )
-                state = self._load_cohort(state, ids)
-                if self.mesh is None:
-                    state, metrics = self._super(state, block, cohort, mask_dev)
-                else:
-                    state, metrics = self._super(state, block, cohort["weights"], mask_dev)
-                self._writeback(state, ids)
-                self._pending.append((round_base, alive, metrics))
-
-                end_round = round_base + self.k2
-                do_eval = (
-                    r.eval_fn is not None
-                    and r.cfg.eval_every
-                    and end_round % r.cfg.eval_every == 0
-                )
-                do_ckpt = (
-                    r.checkpointer is not None
-                    and r.cfg.checkpoint_every
-                    and end_round % r.cfg.checkpoint_every == 0
-                )
-                if do_eval or do_ckpt:
-                    self._flush(wire_per_step)
-                acc = None
-                if do_eval:
-                    # cohort-weighted cloud model; with C == N this is
-                    # bit-identical to the runner's full-population eval
-                    mask_last = None if last_mask is None else jnp.asarray(last_mask)
-                    cloud0 = aggregation.cloud_model(
-                        self._canonical_params(state),
-                        jnp.asarray(self._weights_np[ids]),
-                        mask_last,
+                with StepTraceAnnotation("fed.interval", step_num=interval):
+                    with TraceAnnotation("fed.prefetch_wait", interval=interval, ready=prefetcher.ready):
+                        (ids, cohort, block), snapshot = prefetcher.get()
+                    mask_dev, alive, last_mask = (
+                        static_masks if no_failures else self._masks_for_interval(ids)
                     )
-                    acc = float(r.eval_fn(cloud0))
-                    r.history[-1].accuracy = acc
-                if do_ckpt:
-                    meta = {
-                        "round": end_round,
-                        "batcher": snapshot["batcher"],
-                        "sampler": snapshot["sampler"],
-                    }
-                    if r.failures is not None:
-                        # mask draws for this interval already happened, so
-                        # the simulator state resumes at exactly end_round
-                        meta["failures"] = r.failures.state_dict()
-                    if r.stragglers is not None:
-                        meta["stragglers"] = r.stragglers.state_dict()
-                    fed = state if self.mesh is None else self._unshard_state(state)
-                    save_state = {"fed": fed, "store": r.client_store.state()}
-                    r.checkpointer.save(r.history[-1].step, save_state, meta)
+                    with TraceAnnotation("fed.store_load", interval=interval):
+                        state = self._load_cohort(state, ids)
+                    with TraceAnnotation("fed.dispatch", interval=interval):
+                        if self.mesh is None:
+                            state, metrics = self._super(state, block, cohort, mask_dev)
+                        else:
+                            state, metrics = self._super(state, block, cohort["weights"], mask_dev)
+                    with TraceAnnotation("fed.store_writeback", interval=interval):
+                        self._writeback(state, ids)
+                    self._pending.append((round_base, alive, metrics))
+
+                    end_round = round_base + self.k2
+                    do_eval = (
+                        r.eval_fn is not None
+                        and r.cfg.eval_every
+                        and end_round % r.cfg.eval_every == 0
+                    )
+                    do_ckpt = (
+                        r.checkpointer is not None
+                        and r.cfg.checkpoint_every
+                        and end_round % r.cfg.checkpoint_every == 0
+                    )
+                    if do_eval or do_ckpt:
+                        with TraceAnnotation("fed.flush", interval=interval):
+                            self._flush(wire_per_step)
+                    acc = None
+                    if do_eval:
+                        with TraceAnnotation("fed.eval", interval=interval):
+                            # cohort-weighted cloud model; with C == N this is
+                            # bit-identical to the runner's full-population eval
+                            mask_last = None if last_mask is None else jnp.asarray(last_mask)
+                            cloud0 = aggregation.cloud_model(
+                                self._canonical_params(state),
+                                jnp.asarray(self._weights_np[ids]),
+                                mask_last,
+                            )
+                            acc = float(r.eval_fn(cloud0))
+                        r.history[-1].accuracy = acc
+                    if do_ckpt:
+                        with TraceAnnotation("fed.checkpoint", interval=interval):
+                            meta = {
+                                "round": end_round,
+                                "batcher": snapshot["batcher"],
+                                "sampler": snapshot["sampler"],
+                            }
+                            if r.failures is not None:
+                                # mask draws for this interval already happened, so
+                                # the simulator state resumes at exactly end_round
+                                meta["failures"] = r.failures.state_dict()
+                            if r.stragglers is not None:
+                                meta["stragglers"] = r.stragglers.state_dict()
+                            fed = state if self.mesh is None else self._unshard_state(state)
+                            save_state = {"fed": fed, "store": r.client_store.state()}
+                            r.checkpointer.save(r.history[-1].step, save_state, meta)
                 if acc is not None and r.cfg.target_accuracy and acc >= r.cfg.target_accuracy:
                     stopped = True
                     break
-            self._flush(wire_per_step)
+            with TraceAnnotation("fed.flush", interval=interval):
+                self._flush(wire_per_step)
         finally:
             prefetcher.stop()
         if self.mesh is not None:
