@@ -24,7 +24,7 @@ def main(csv=True):
     x = jnp.asarray(rng.normal(size=(32, 1 << 20)), jnp.float32)
     w = jnp.asarray(rng.uniform(1, 2, size=32), jnp.float32)
     t_ref, out_ref = timed(lambda: ref.grouped_mean_ref(x, w, 8), iters=3)
-    ok = checks["hier_aggregate"] = bool(np.allclose(ops.grouped_mean(x, w, 8), out_ref, atol=1e-5))
+    ok = checks["hier_aggregate"] = bool(np.allclose(ops.grouped_mean(x, w, (4,)), out_ref, atol=1e-5))
     # traffic: kernel = 2 passes (read+write) vs ref ~4 passes
     print(f"kernel_hier_aggregate,ref_us={t_ref*1e6:.0f},allclose={ok},hbm_passes=2_vs_4")
 
@@ -35,7 +35,7 @@ def main(csv=True):
     spec = parse_fanouts("8,6,6,4,3,2,2,1/8")
     seg = spec.segments(1)
     xs = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-    t_uni, _ = timed(lambda: ops.grouped_mean(xs, w, 8, block_d=bd), iters=5)
+    t_uni, _ = timed(lambda: ops.grouped_mean(xs, w, (n // 8,)), iters=5)
     t_rag, out_rag = timed(lambda: ops.segment_mean(xs, w, seg, 8, block_d=bd), iters=5)
     ok = checks["hier_aggregate_ragged"] = bool(
         np.allclose(out_rag, ref.segment_mean_ref(xs, w, seg, 8, block_d=bd), atol=1e-5)

@@ -98,6 +98,8 @@ def kernel_phase(jax, jnp, *, d: int = 1 << 22, seq: int = 1024, width: int = 40
     h0 = jnp.asarray(rng.normal(size=(2, width)), jnp.float32)
     qkv = [jnp.asarray(rng.normal(size=(24, seq, 64)), jnp.bfloat16) for _ in range(3)]
     ne, cpe, k1, bs, feat, out = 4, 8, 8, 8, 128, 256
+    # one lm-100m layer-stacked leaf, 2 edges x 2 clients: the cloud sync's shape
+    leaf = jnp.asarray(rng.normal(size=(4, 12, 768, 2048)), jnp.float32)
     mp = jnp.asarray(rng.normal(size=(ne * cpe, feat * out)) * 0.05, jnp.float32)
     mx = jnp.asarray(rng.normal(size=(ne * cpe, k1, bs, feat)), jnp.float32)
     my = jnp.asarray(rng.normal(size=(ne * cpe, k1, bs, out)), jnp.float32)
@@ -107,8 +109,11 @@ def kernel_phase(jax, jnp, *, d: int = 1 << 22, seq: int = 1024, width: int = 40
 
     # (name, kernel fn, args, oracle fn, tolerance); oracles in full f32
     cases = [
-        ("grouped_mean", lambda x, w: ops.grouped_mean(x, w, 4), (x, w),
+        ("grouped_mean", lambda x, w: ops.grouped_mean(x, w, (4,)), (x, w),
          lambda x, w: ref.grouped_mean_ref(x, w, 4), dict(rtol=1e-5, atol=1e-5)),
+        ("grouped_mean_cloud", lambda x, w: ops.grouped_mean(x, w, (2, 2)), (leaf, w[:4]),
+         lambda x, w: ref.grouped_mean_ref(ref.grouped_mean_ref(x.reshape(4, -1), w, 2), w, 1).reshape(x.shape),
+         dict(rtol=1e-5, atol=1e-5)),
         ("segment_mean", lambda x, w: ops.segment_mean(x, w, seg, 4), (x, w),
          lambda x, w: ref.segment_mean_ref(x, w, seg, 4, block_d=d), dict(rtol=1e-5, atol=1e-5)),
         ("segment_dequant_mean", lambda q, s, w: ops.segment_dequant_mean(q, s, w, seg, 4), (q, s, w),

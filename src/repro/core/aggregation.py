@@ -41,6 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import hier_aggregate as _ha
+from repro.kernels import ops
+
 PyTree = Any
 
 
@@ -208,12 +211,48 @@ def hierarchical_segment_mean(
     over groups with weights |D^g|) — but kept staged so GSPMD emits the
     hierarchical reduce(ICI) -> reduce(DCN) schedule. ``level=None`` means
     the top (cloud) level.
+
+    On TPU, where every level up to ``level`` is static equal contiguous
+    blocks, each leaf that tiles (``kernels.hier_aggregate.group_mean_plan``)
+    takes every stage in one in-place Pallas pass (``ops.grouped_mean``):
+    XLA would read and write the stack once per reduce, divide and select.
+    Elsewhere — the CPU backend, ragged trees, leaves that do not tile —
+    the stages run as jnp.
     """
     lvl = spec.depth if level is None else level
-    out = tree
-    for t in range(1, lvl + 1):
-        out = segment_weighted_mean(out, weights, spec.segments(t), spec.num_nodes(t), mask)
-    return out
+
+    def staged(t):
+        out = t
+        for s in range(1, lvl + 1):
+            out = segment_weighted_mean(out, weights, spec.segments(s), spec.num_nodes(s), mask)
+        return out
+
+    fanouts = _uniform_fanouts(spec, lvl) if jax.default_backend() == "tpu" else None
+    if fanouts is None:
+        return staged(tree)
+    w = weights.astype(jnp.float32)
+    if mask is not None:
+        w = w * mask.astype(jnp.float32)
+
+    def leaf_fn(x):
+        if _ha.group_mean_plan(x.shape, x.dtype) is None:
+            return staged(x)
+        return ops.grouped_mean(x, w, fanouts)
+
+    return jax.tree_util.tree_map(leaf_fn, tree)
+
+
+def _uniform_fanouts(spec, level: int) -> Optional[Tuple[int, ...]]:
+    """Each level's fan-out, bottom-up to ``level``, where every level's
+    segment ids are static equal contiguous blocks; else None."""
+    fanouts, below = [], spec.num_clients
+    for t in range(1, level + 1):
+        groups = _static_uniform_groups(spec.segments(t), spec.num_nodes(t))
+        if groups is None:
+            return None
+        fanouts.append(below // groups)
+        below = groups
+    return tuple(fanouts)
 
 
 def group_weights(weights: jnp.ndarray, num_groups: int, mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:

@@ -25,20 +25,21 @@ def use_interpret() -> bool:
     raise RuntimeError(f"Pallas kernels run compiled on TPU or interpreted on CPU, not on {backend!r}")
 
 
-def grouped_mean(x, weights, num_groups, *, block_d: int = 512):
-    return _ha.grouped_mean_pallas(
-        x, weights, num_groups, block_d=block_d, interpret=use_interpret()
-    )
+def grouped_mean(x, weights, fanouts):
+    """The staged weighted mean of a uniform tree over one (N, ...) leaf,
+    broadcast back in place: ``fanouts`` bottom-up, one per level up to the
+    synced one (``(N // G,)`` is G equal groups)."""
+    return _ha.grouped_mean_pallas(x, weights, fanouts, interpret=use_interpret())
 
 
 def segment_mean(x, weights, segment_ids, num_segments, *, block_d: int = 512):
     """Ragged-group aggregation over sorted segment ids (N,). Dispatches to
-    the uniform reshape kernel when the ids form equal contiguous blocks
+    the uniform kernel when the ids form equal contiguous blocks
     (same predicate as the jnp path in core.aggregation)."""
     from repro.core.aggregation import _static_uniform_groups
 
     if _static_uniform_groups(segment_ids, num_segments) is not None:
-        return grouped_mean(x, weights, num_segments, block_d=block_d)
+        return grouped_mean(x, weights, (x.shape[0] // num_segments,))
     return _ha.segment_mean_pallas(
         x, weights, segment_ids, num_segments, block_d=block_d, interpret=use_interpret()
     )

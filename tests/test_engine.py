@@ -123,6 +123,21 @@ def test_superround_bitexact_two_level(rng, kappa1, kappa2):
     np.testing.assert_allclose(m1["grad_norm"], m2["grad_norm"], rtol=1e-6)
 
 
+@pytest.mark.parametrize("backend,kernels", [("cpu", 0), ("tpu", 2)])
+def test_superround_syncs_take_the_kernel_on_tpu_only(monkeypatch, backend, kernels):
+    """On the CPU backend the superround's syncs stay jnp (no pallas_call in
+    its jaxpr), so the bit-exactness tests here compare the jnp path; on TPU
+    the edge and the cloud branch each take the group mean kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    topo = FedTopology(num_edges=2, clients_per_edge=2)
+    cfg = HierFAVGConfig(kappa1=2, kappa2=2)
+    loss_fn = lambda p, b, _rng: 0.5 * jnp.sum((p["w"] - b["c"]) ** 2)
+    state = init_state(jax.random.PRNGKey(0), {"w": jnp.zeros((8, 128))}, sgd(0.1), topo, cfg)
+    block = {"c": jnp.zeros((2, 2, 4, 8, 128))}
+    fn = build_super_round(loss_fn, sgd(0.1), topo, cfg, jnp.ones(4))
+    assert str(jax.make_jaxpr(fn)(state, block, None)).count("pallas_call[") == kernels
+
+
 def test_superround_bitexact_masks(rng):
     """Per-round survival masks == the (κ₂, N) stacked mask scan, including
     a round where a whole edge dies."""

@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.core import aggregation
+from repro.core.hierarchy import HierarchySpec
+from repro.kernels import hier_aggregate, ops, ref
 
 
 @pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)])
@@ -27,7 +29,7 @@ def test_interpret_mode_follows_the_platform(monkeypatch, backend, interpret):
 def test_grouped_mean_sweep(rng, n, groups, d, dtype):
     x = jnp.asarray(rng.normal(size=(n, d)), dtype)
     w = jnp.asarray(rng.uniform(0.5, 4.0, size=n), jnp.float32)
-    got = ops.grouped_mean(x, w, groups, block_d=128)
+    got = ops.grouped_mean(x, w, (n // groups,))
     want = ref.grouped_mean_ref(x, w, groups)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(
@@ -38,10 +40,67 @@ def test_grouped_mean_sweep(rng, n, groups, d, dtype):
 def test_grouped_mean_masked_and_dead_group(rng):
     x = jnp.asarray(rng.normal(size=(8, 256)), jnp.float32)
     w = jnp.asarray(rng.uniform(1, 2, size=8), jnp.float32).at[:4].set(0.0)
-    got = ops.grouped_mean(x, w, 2)
+    got = ops.grouped_mean(x, w, (4,))
     want = ref.grouped_mean_ref(x, w, 2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     np.testing.assert_allclose(np.asarray(got[:4]), np.asarray(x[:4]))  # dead group
+
+
+# (tree fan-outs, level): edge and cloud of 2 edges x 2 clients, and the
+# region and cloud levels of a uniform 2 x 2 x 2 tree
+_TREES = {"2x2": [[2, 2], [2]], "2x2x2": [[2] * 4, [2, 2], [2]]}
+
+
+@pytest.mark.parametrize("tree,level", [("2x2", 1), ("2x2", 2), ("2x2x2", 2), ("2x2x2", 3)])
+@pytest.mark.parametrize(
+    "shape",
+    # (N, L, a, b); (N, a, b) with a partial last block of rows and of lanes;
+    # (N, d); (N, D) with a partial last block of lanes
+    [(3, 16, 200), (100, 1030), (300,), (8492,)],
+    ids=["layers", "partial_rows", "flat", "flat_partial"],
+)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("mask", ["none", "partial", "dead_group"])
+def test_grouped_mean_levels_match_the_jnp_stages(rng, monkeypatch, tree, level, shape, dtype, mask):
+    """``hierarchical_segment_mean`` on TPU (the kernel, interpreted here)
+    against its jnp stages on CPU: every level up to ``level`` in one pass,
+    the same masked weights, dead groups keeping their rows."""
+    spec = HierarchySpec.from_fanouts(_TREES[tree])
+    n = spec.num_clients
+    x = jnp.asarray(rng.normal(size=(n,) + shape), dtype)
+    w = jnp.asarray(rng.uniform(0.5, 4.0, size=n), jnp.float32)
+    m = None if mask == "none" else np.ones(n, np.float32)
+    if mask == "partial":
+        m[1] = 0.0
+    elif mask == "dead_group":
+        m[:2] = 0.0  # the whole first edge
+    m = None if m is None else jnp.asarray(m)
+    want = aggregation.hierarchical_segment_mean(x, w, spec, level, m)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "use_interpret", lambda: True)
+    got = jax.jit(lambda x, w, m: aggregation.hierarchical_segment_mean(x, w, spec, level, m))(x, w, m)
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 else dict(rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **tol)
+    if mask == "dead_group" and level == 1:
+        np.testing.assert_array_equal(np.asarray(got[:2]), np.asarray(x[:2]))
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,tiles",
+    [((4, 12, 768, 2048), jnp.float32, True), ((4, 768), jnp.bfloat16, True),
+     ((4,), jnp.float32, False), ((4, 5, 5, 3, 16), jnp.float32, False),
+     ((4, 256), jnp.int32, False), ((4096, 300), jnp.float32, False)],
+    ids=["layers", "flat_bf16", "scalar", "conv_kernel", "int", "too_many_clients"],
+)
+def test_group_mean_plan_tiles_or_declines(shape, dtype, tiles):
+    """Leaves that do not tile — scalars, a merge of middle dims that would
+    relayout, non-float dtypes, more clients than a 1 MiB block holds — get
+    no plan (the jnp stages take them); a plan's blocks stay within 1 MiB."""
+    plan = hier_aggregate.group_mean_plan(shape, dtype)
+    assert (plan is not None) is tiles
+    if plan is not None:
+        assert int(np.prod(plan.block)) * jnp.dtype(dtype).itemsize <= 1 << 20
+        assert plan.view[0] == shape[0] and int(np.prod(plan.view)) == int(np.prod(shape))
 
 
 @pytest.mark.parametrize("s,window", [(128, 0), (200, 0), (256, 33), (120, 64)])

@@ -10,6 +10,7 @@ is off around these compiles, since entries written for a described chip
 cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,7 +64,7 @@ def _kernel_cases():
     f32, i8, bf16 = jnp.float32, jnp.int8, jnp.bfloat16
     mu = (N * 2, FEAT * OUT)
     return {
-        "grouped_mean": (lambda x, w: ops.grouped_mean(x, w, 4), [((N, D), f32), ((N,), f32)]),
+        "grouped_mean": (lambda x, w: ops.grouped_mean(x, w, (N // 4,)), [((N, D), f32), ((N,), f32)]),
         "segment_mean": (
             lambda x, w: ops.segment_mean(x, w, SEGMENTS, 4), [((N, D), f32), ((N,), f32)]
         ),
@@ -104,9 +105,9 @@ def test_kernel_compiles_for_v5e(one_chip, compiled_kernels, name):
     assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel, not a fallback
 
 
-def test_lm10m_superround_compiles_for_v5e(one_chip):
-    """One cloud interval of the lm_edge_niid scenario (lm-10m, 8 clients /
-    2 edges), the program the superround engine dispatches, fits one chip."""
+def _lm10m_superround(one_chip):
+    """The lm_edge_niid superround (lm-10m, 8 clients / 2 edges), compiled
+    for one described v5e chip, and its parameter leaves' count."""
     from repro.core.hierfavg import build_super_round, init_state
     from repro.fed import scenarios
 
@@ -127,5 +128,58 @@ def test_lm10m_superround_compiles_for_v5e(one_chip):
     compiled = jax.jit(fn, donate_argnums=(0,)).lower(
         jax.tree_util.tree_map(put, state), block, None
     ).compile()
+    return compiled, len(jax.tree_util.tree_leaves(state.params))
+
+
+def test_lm10m_superround_compiles_for_v5e(one_chip):
+    """One cloud interval of the lm_edge_niid scenario (lm-10m, 8 clients /
+    2 edges), the program the superround engine dispatches, fits one chip."""
+    compiled, _ = _lm10m_superround(one_chip)
     mem = compiled.memory_analysis()
     assert 0 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 << 30
+
+
+def _group_mean_calls(hlo: str) -> dict:
+    """The ``hier_group_mean`` Mosaic calls of an HLO text, counted by the
+    sync scope in their op_name (None: in no sync scope)."""
+    counts: dict = {}
+    for line in hlo.splitlines():
+        if "tpu_custom_call" not in line or "hier_group_mean" not in line:
+            continue
+        scope = re.search(r'op_name="[^"]*(hierfavg\.sync\.\w+)', line)
+        key = scope.group(1) if scope else None
+        counts[key] = counts.get(key, 0) + 1
+    return counts
+
+
+def test_lm10m_superround_syncs_run_the_group_mean_kernel(one_chip, monkeypatch):
+    """On TPU each sync branch of the superround holds one in-place
+    ``hier_group_mean`` kernel per parameter leaf, named under its sync's
+    scope (the scope the benchmark's sync metrics read)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # what the dispatch asks
+    compiled, leaves = _lm10m_superround(one_chip)
+    calls = _group_mean_calls(compiled.as_text())
+    assert calls == {"hierfavg.sync.edge": leaves, "hierfavg.sync.cloud": leaves}
+
+
+@pytest.mark.parametrize("fanouts,per_sync", [("5,4,4,3/4", 0), ("4,4,4,4/4", 1)])
+def test_ragged_tree_lowers_without_the_group_mean_kernel(one_chip, monkeypatch, fanouts, per_sync):
+    """A ragged tree keeps the jnp stages on TPU too; the uniform tree of
+    the same size takes the kernel in each of its two syncs."""
+    from repro.core import HierFAVGConfig
+    from repro.core.hierfavg import build_super_round, init_state
+    from repro.optim import sgd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    topo = parse_fanouts(fanouts)
+    cfg = HierFAVGConfig(kappa1=2, kappa2=2)
+    n = topo.num_clients
+    loss_fn = lambda p, b, _rng: 0.5 * jnp.sum((p["w"] - b["c"]) ** 2)
+    state = jax.eval_shape(
+        lambda k: init_state(k, {"w": jnp.zeros((8, 128))}, sgd(0.1), topo, cfg), jax.random.PRNGKey(0)
+    )
+    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    block = {"c": jax.ShapeDtypeStruct((2, 2, n, 8, 128), jnp.float32, sharding=one_chip)}
+    fn = build_super_round(loss_fn, sgd(0.1), topo, cfg, jnp.ones(n))
+    text = jax.jit(fn).lower(jax.tree_util.tree_map(put, state), block, None).as_text()
+    assert text.count("hier_group_mean") == 2 * per_sync
