@@ -152,8 +152,10 @@ def run(
         for gi, dev in enumerate(devices):
             p = cast(jax.device_put(params0, dev))
             P.append(p)
-            M.append(zeros(p) if kind == "adam" else None)
-            V.append(zeros(p) if kind == "adam" else None)
+            # zeros read no input, so they land on the default device unless told
+            with jax.default_device(dev):
+                M.append(zeros(p) if kind == "adam" else None)
+                V.append(zeros(p) if kind == "adam" else None)
             W.append(jax.device_put(weights[gi * g_size:(gi + 1) * g_size], dev))
         count = [jax.device_put(jnp.zeros([], jnp.int32), dev) for dev in devices]
         order = BatchOrder(data["parts"], traffic["batch_size"], seed)

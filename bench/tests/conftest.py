@@ -20,17 +20,24 @@ import json  # noqa: E402
 import pytest  # noqa: E402
 
 FIX = Path(__file__).resolve().parent / "fixtures"
-# each benchmark cell at test size: its own name and limits, its config's
-# layer types at small widths, its traffic's shape with fewer rows
+LIMITS = REPO / "bench" / "limits"
+# each benchmark cell at test size: its own name, its config's layer types at
+# small widths, its traffic's shape with fewer rows, and its limits: those
+# read on the chip, or for a cell with no chip readings, limits read on the
+# CPU at this size (``fixtures/limits``)
 TEST_CELLS = {
-    "lm100m.s128": ("lm-100m", "lm-tiny.json", "traffic-lm", 1),
-    "cifar_cnn.k5x10": ("paper-cifar10-cnn", "cnn-tiny.json", "traffic-cnn", 1),
-    "lm100m.s128.mesh4": ("lm-100m", "lm-tiny.json", "traffic-lm-mesh4", 4),
+    "lm100m.s128": ("lm-100m", "lm-tiny.json", "traffic-lm", 1, LIMITS),
+    "cifar_cnn.k5x10": ("paper-cifar10-cnn", "cnn-tiny.json", "traffic-cnn", 1, FIX / "limits"),
+    "lm100m.s128.mesh4": ("lm-100m", "lm-tiny.json", "traffic-lm-mesh4", 4, LIMITS),
 }
 
 
+def limits_dir(cell: str) -> Path:
+    return TEST_CELLS[cell][4]
+
+
 def small_bench(cell: str) -> dict:
-    config, file, traffic, chips = TEST_CELLS[cell]
+    config, file, traffic, chips, _ = TEST_CELLS[cell]
     real = json.loads((REPO / "BENCHMARK.json").read_text())
     return {
         "configs": [{"name": config, "file": f"bench/tests/fixtures/{file}"}],
@@ -48,7 +55,8 @@ def run_small(capsys):
 
     def go(cell: str, seed: int = 20260917, seconds: float = 1.0):
         rc = run.main(["--workload", cell, "--seed", str(seed), "--seconds", str(seconds)],
-                      require_chip=False, bench=small_bench(cell), traffic_dir=FIX)
+                      require_chip=False, bench=small_bench(cell), traffic_dir=FIX,
+                      limits_dir=limits_dir(cell))
         lines = capsys.readouterr().out.strip().splitlines()
         return rc, json.loads(lines[-1])
 

@@ -12,12 +12,12 @@ import pytest
 
 from bench import check, run
 
-from conftest import FIX, small_bench
+from conftest import FIX, limits_dir, small_bench
 
 
 @pytest.mark.parametrize("cell", ["lm100m.s128", "cifar_cnn.k5x10"])
 def test_bf16_control_fails_and_program_passes(cell):
-    spec = run.load_cell(cell, small_bench(cell), FIX, run.BENCH / "limits")
+    spec = run.load_cell(cell, small_bench(cell), FIX, limits_dir(cell))
     seed = 777
     prog = run.program_setup(jax, spec, seed, jax.devices()[:1])
     args = (spec["sizes"], spec["traffic"], prog["data"], seed, jax.devices()[:1])
